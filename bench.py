@@ -25,10 +25,10 @@ Two accuracy/throughput comparisons, both on identical inputs:
   a full uncapped run) — and carries the recording for the rest, each
   service flagged ``measured`` true/false.
 
-The timed pass runs under ``jax.profiler`` and the trace is parsed
-in-process (``jax.profiler.ProfileData``): ``device_busy_s_measured`` /
-``mfu_measured_pct`` come from the device plane's executed-op timeline,
-not wall-clock inference (committed as PROFILE_r{N}.json).
+The timed pass runs under ``jax.profiler``; ``TW_BENCH_PROFILE_DIR``
+keeps the trace, which ``benchmark/trace.py`` reduces. This script reads
+no trace: ``device_busy_s_measured``, ``mfu_measured_pct`` and the other
+profile fields stay null.
 
 Prints ONE JSON line with the TPU spans/sec and the vs-baseline ratio.
 
@@ -200,88 +200,6 @@ def subset_problem(prob, n):
 # ---------------------------------------------------------------------------
 # Solver child (runs under whichever JAX backend the env selects)
 # ---------------------------------------------------------------------------
-
-def _parse_profile(profile_dir):
-    """Device-plane busy time + top self-time ops from the xplane trace.
-
-    Returns None when this jax version cannot deserialize xplane traces
-    in-process (``jax.profiler.ProfileData`` not exported — the feature
-    check lives in obs/profile.py so the bench test can skip cleanly
-    instead of erroring)."""
-    import glob
-
-    from traceweaver_tpu.obs.profile import profile_data_available
-
-    if not profile_data_available():
-        return None
-
-    from jax.profiler import ProfileData
-
-    paths = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if not paths:
-        return None
-    with open(sorted(paths)[-1], "rb") as f:
-        data = ProfileData.from_serialized_xspace(f.read())
-    busy_ns = 0.0
-    ops = {}
-    source = None
-    for plane in data.planes:
-        name = plane.name or ""
-        if not (name.startswith("/device:") or "TPU" in name.upper()):
-            continue
-        for line in plane.lines:
-            lname = (line.name or "").lower()
-            # "XLA Modules" spans whole executables (busy time);
-            # "XLA Ops" has per-op self time (the roofline breakdown)
-            if "module" in lname:
-                for ev in line.events:
-                    busy_ns += ev.duration_ns
-                    source = "device_plane"
-            elif "op" in lname:
-                for ev in line.events:
-                    ops[ev.name] = ops.get(ev.name, 0.0) + ev.duration_ns
-                    source = "device_plane"
-    if source is None:
-        # CPU backend: no populated device plane — XLA op executions live
-        # on the host plane's tf_XLA* executor thread lines. Busy time is
-        # the exact ThunkExecutor::Execute run spans, summed across
-        # worker threads (so it can exceed wall-clock; the caller-side
-        # "... (wait for completion)" idle spans are excluded — they
-        # would double-count time the workers' spans already cover).
-        # Per-op durations are INCLUSIVE (while.* events contain their
-        # body ops) — reported under "dur_s", not "self_s", so consumers
-        # cannot mistake the CPU containment profile for additive
-        # self-time.
-        for plane in data.planes:
-            if (plane.name or "") != "/host:CPU":
-                continue
-            for line in plane.lines:
-                lname = line.name or ""
-                if not (lname.startswith("tf_XLA")
-                        or "xla-cpu-codegen" in lname):
-                    continue
-                for ev in line.events:
-                    if ev.name == "ThunkExecutor::Execute":
-                        busy_ns += ev.duration_ns
-                        source = "host_cpu_xla_threads"
-                    elif ev.name.startswith("ThunkExecutor::Execute"):
-                        continue  # caller-side wait span
-                    else:
-                        ops[ev.name] = ops.get(ev.name, 0.0) + ev.duration_ns
-                        source = "host_cpu_xla_threads"
-    if source is None:
-        return None
-    dur_key = "self_s" if source == "device_plane" else "dur_s"
-    top = sorted(ops.items(), key=lambda kv: -kv[1])[:12]
-    return {
-        "device_busy_s": busy_ns / 1e9,
-        "profile_source": source,
-        "top_ops": [
-            {"op": k[:120], dur_key: round(v / 1e9, 4)} for k, v in top
-        ],
-    }
-
 
 def run_solver_child(bundle_path: str, out_path: str) -> None:
     with open(bundle_path, "rb") as f:
@@ -579,41 +497,18 @@ def run_solver_child(bundle_path: str, out_path: str) -> None:
     # host contention with this CPU work and inflate the headline ratio
     # (the measurement-protecting atomic report writes above already make
     # a mid-enrichment kill lose nothing but enrichment itself)
-    profile = None
-    try:
-        profile = _parse_profile(profile_dir)
-    except Exception as e:  # trace formats vary per backend plugin
-        log(f"child: profile parse failed: {type(e).__name__}: {e}")
     log(f"child: profiler trace in {profile_dir}")
     if auto_profile_dir:
         import shutil
 
         shutil.rmtree(profile_dir, ignore_errors=True)
 
-    busy_measured = (profile or {}).get("device_busy_s") or 0.0
-    profile_source = (profile or {}).get("profile_source")
-    report["device_busy_s_measured"] = (busy_measured if busy_measured > 0
-                                        else None)
-    report["profile_source"] = profile_source
-    report["profile_top_ops"] = (profile or {}).get("top_ops")
-    # "measured" MFU comes ONLY from a real device plane: the CPU
-    # fallback's busy time is summed across XLA worker threads (can
-    # exceed wall-clock), which would silently deflate a "measured"
-    # utilization — on that path the metric stays null and the estimate
-    # (wall-clock denominator) is the number to read
-    report["mfu_measured_pct"] = (
-        round(100.0 * flops / busy_measured / peaks["flops"], 4)
-        if peaks and busy_measured > 0 and profile_source == "device_plane"
-        else None)
-    device_s = (busy_measured
-                if busy_measured > 0 and profile_source == "device_plane"
-                else device_s_wall)
-    # with a real device plane, the overlap metric stops being a proxy:
-    # measured busy time over the timed pass's wall-clock
-    if busy_measured > 0 and profile_source == "device_plane":
-        report["pipeline_overlap_pct"] = round(
-            100.0 * min(busy_measured, solve_time) / max(solve_time, 1e-9),
-            2)
+    # the trace is read by benchmark/trace.py, not here
+    report["device_busy_s_measured"] = None
+    report["profile_source"] = None
+    report["profile_top_ops"] = None
+    report["mfu_measured_pct"] = None
+    device_s = device_s_wall
 
     # --- Pallas kernel on-device proof (non-interpret) -------------------
     pallas_ok = None

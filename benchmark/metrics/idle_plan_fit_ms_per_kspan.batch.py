@@ -1,0 +1,9 @@
+"""Device idle ms per 1000 traced spans while the host was in the
+program's ``tw:fleet:plan-fit`` stage (``benchmark/idle_attribution.py``):
+the part of ``plan_fit_s`` that left the chip waiting."""
+
+from benchmark import idle_attribution
+
+
+def read(m):
+    return idle_attribution.stage_idle_ms_per_kspan(m, ["plan-fit"])

@@ -27,36 +27,6 @@ def bench(monkeypatch):
     return importlib.reload(bench_mod)
 
 
-def test_parse_profile_cpu_fallback(bench, tmp_path):
-    """A real CPU-backend trace must parse through the /host:CPU tf_XLA*
-    fallback: nonzero busy time, op table without ThunkExecutor wrapper
-    events, and dur_s (containment) keys — not self_s. Skips (instead of
-    erroring) on jax versions that do not export ProfileData — the same
-    feature check _parse_profile gates on in production."""
-    from traceweaver_tpu.obs.profile import profile_data_available
-
-    if not profile_data_available():
-        pytest.skip("jax.profiler.ProfileData unavailable on this jax "
-                    "version (bench._parse_profile returns None)")
-    import jax
-    import jax.numpy as jnp
-
-    jax.profiler.start_trace(str(tmp_path))
-    jax.jit(lambda x: (x @ x).sum())(jnp.ones((256, 256))).block_until_ready()
-    jax.profiler.stop_trace()
-
-    prof = bench._parse_profile(str(tmp_path))
-    assert prof is not None
-    assert prof["profile_source"] == "host_cpu_xla_threads"
-    # tiny programs may run entirely on codegen threads without
-    # ThunkExecutor spans (busy 0); the op table is the load-bearing part
-    assert prof["device_busy_s"] >= 0
-    assert prof["top_ops"], "expected at least one op"
-    for op in prof["top_ops"]:
-        assert "dur_s" in op and "self_s" not in op
-        assert not op["op"].startswith("ThunkExecutor")
-
-
 def test_baseline_child_carries_recording_for_over_alarm_services(
         bench, tmp_path, monkeypatch):
     """Budget logic: services whose recorded cost exceeds the alarm carry
@@ -541,18 +511,6 @@ def test_ingest_leg_small_run_parity_and_fields(bench, monkeypatch):
     assert report["pack_spans_per_s"] > 0
     assert report["pack_spans_per_s_object"] > 0
     assert report["pack_columnar_speedup"] > 0
-
-
-def test_parse_profile_none_when_profiledata_missing(bench, tmp_path,
-                                                     monkeypatch):
-    """The ProfileData feature gate: on jax versions without the export,
-    _parse_profile degrades to None (profile fields stay null) instead
-    of raising ImportError mid-enrichment."""
-    import traceweaver_tpu.obs.profile as obs_profile
-
-    monkeypatch.setattr(obs_profile, "profile_data_available",
-                        lambda: False)
-    assert bench._parse_profile(str(tmp_path)) is None
 
 
 def test_telemetry_fields_agreement_and_mismatch(bench):

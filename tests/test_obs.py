@@ -540,14 +540,3 @@ def test_profile_knobs_registered_and_annotate_inert(monkeypatch):
         assert all(isinstance(v, float) for _, v in samples)
     monkeypatch.setenv("TW_PROFILE", "nonsense-is-truthy")
     assert obs_profile.enabled() is True
-
-
-def test_profile_data_feature_check_matches_import():
-    from traceweaver_tpu.obs.profile import profile_data_available
-
-    try:
-        from jax.profiler import ProfileData  # noqa: F401
-        expected = True
-    except ImportError:
-        expected = False
-    assert profile_data_available() is expected

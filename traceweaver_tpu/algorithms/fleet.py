@@ -104,6 +104,10 @@ def _fleet_budget_elems() -> int:
 def _fleet_budget_bytes() -> int:
     return _fleet_budget_elems() * 4
 
+
+#: the profiler span prefix of a fleet stage (``tw:fleet:<stage>``)
+SPAN_PREFIX = "tw:fleet:"
+
 # window-axis keys of a packed fleet batch, dispatch argument order
 _BATCH_KEYS = ("in_start", "in_end", "in_valid", "out_start", "out_end",
                "out_valid", "skip_cap", "force_skip")
@@ -226,11 +230,18 @@ class _Stats:
     gets its OWN local stats dict — and therefore its own ``_Stats``
     instance and lock — so ticket dispatches never contend here; the
     per-ticket dicts are folded into the service ledger under the
-    service lock at complete (serve/tenancy.py ``_merge_stats``)."""
+    service lock at complete (serve/tenancy.py ``_merge_stats``).
+
+    ``profile`` is ``TW_PROFILE``, read once per solve: the stage timer
+    (:func:`traceweaver_tpu.obs.profile.stage`) opens its profiler span
+    on it, named ``span_prefix`` and the stage."""
+
+    span_prefix = SPAN_PREFIX
 
     def __init__(self, d: Optional[Dict[str, float]]):
         self.d = d
         self._lock = threading.Lock()
+        self.profile = _profile.enabled()
 
     def add(self, key: str, val: float = 1.0) -> None:
         _OBS_LEDGER.inc(val, key=key)
@@ -288,18 +299,17 @@ def _as_stats(stats) -> _Stats:
     return stats if isinstance(stats, _Stats) else _Stats(stats)
 
 
-def _trace_stage(keys, stage: str, w0_us: float,
-                 w1_us: Optional[float] = None) -> None:
-    """Record one pipeline stage on every window trace in ``keys``
-    (obs/selftrace.py). ``keys`` is the group's host-side trace context
-    — carried on the dispatch ticket so pack thread, flow workers, and
-    decode workers all stamp the same windows. One global read and out
-    when no tracer is installed (the production default)."""
+def _trace_rung(keys, rung: str) -> None:
+    """Record a supervisor ladder rung, an instant, on every window
+    trace in ``keys`` (obs/selftrace.py; the timed stages stamp
+    themselves through the stage timer). One global read and out when no
+    tracer is installed (the production default)."""
     tr = _selftrace.active()
     if tr is None or not keys:
         return
+    t_us = _selftrace.now_us()
     for key in keys:
-        tr.stage(key, stage, w0_us, w1_us)
+        tr.stage(key, rung, t_us)
 
 
 def _note_aot(st: "_Stats", shape: Optional[str]) -> None:
@@ -320,9 +330,12 @@ def _copy_async(out) -> None:
         pass
 
 
-def _fetch(handle, st: _Stats, flow_wait=None, flag_fetch: bool = False):
-    """Blocking device fetch: billed to ``wait_s`` (the device-execution
-    proxy stage) and to the D2H byte ledger ``d2h_bytes_fetched``. Flag
+def _fetch(handle, st: _Stats, flow_wait=None, flag_fetch: bool = False,
+           trace_keys=(), span_args=None):
+    """Blocking device fetch: the ``fetch`` stage (``flag-fetch`` for a
+    convergence-flag fetch, stamped ``compact-fetch`` on the windows'
+    self-traces), billed to ``wait_s`` (the device-execution proxy
+    stage) and to the D2H byte ledger ``d2h_bytes_fetched``. Flag
     fetches additionally land in ``d2h_bytes_flags``, making the
     compaction contract — O(B) bytes to learn the convergence set, not
     the whole packed block — auditable from stats alone. ``flow_wait``
@@ -330,22 +343,23 @@ def _fetch(handle, st: _Stats, flow_wait=None, flag_fetch: bool = False):
     dispatcher can subtract it from its launch-time accounting without
     reading the shared dict back."""
     _fault_check("fetch", st)
-    t0 = time.perf_counter()
-    out = np.asarray(handle)
-    dt = time.perf_counter() - t0
-    st.add("wait_s", dt)
+    with _profile.stage(st, "flag-fetch" if flag_fetch else "fetch",
+                        "wait_s", trace_keys, trace_as="compact-fetch",
+                        **(span_args or {})) as t:
+        out = np.asarray(handle)
     if flow_wait is not None:
         # twlint: disable=TW007 — flow-local wait aggregator (a 1-element
         # list returned to the dispatcher), not a ledger counter; the
-        # telemetry copy is the st.add("wait_s") mirror above
-        flow_wait[0] += dt
+        # telemetry copy is the stage timer's wait_s bill above
+        flow_wait[0] += t.seconds
     st.add("d2h_bytes_fetched", float(out.nbytes))
     if flag_fetch:
         st.add("d2h_bytes_flags", float(out.nbytes))
     return out
 
 
-def _fetch_flags(flags, st: _Stats, flow_wait, mesh=None):
+def _fetch_flags(flags, st: _Stats, flow_wait, mesh=None, trace_keys=(),
+                 span_args=None):
     """Convergence-flag fetch for one dispatch group, coalesced.
 
     Single device: one blocking fetch of the ``[B]`` bool array (O(B)
@@ -361,7 +375,8 @@ def _fetch_flags(flags, st: _Stats, flow_wait, mesh=None):
 
         flags = coalesce_to_device0(flags, mesh)
     st.add("d2h_flag_fetches", 1.0)
-    return _fetch(flags, st, flow_wait, flag_fetch=True)
+    return _fetch(flags, st, flow_wait, flag_fetch=True,
+                  trace_keys=trace_keys, span_args=span_args)
 
 
 class FleetItem:
@@ -546,7 +561,8 @@ def _run_fallback(entries, results, all_spans, all_processes,
         )
         return i, out, algo.stats, algo.per_span_confidence
 
-    with ThreadPoolExecutor(max_workers=max(1, len(entries))) as pool:
+    with _profile.stage(st, "fallback", items=len(entries)), \
+            ThreadPoolExecutor(max_workers=max(1, len(entries))) as pool:
         for i, out, solver_stats, conf in pool.map(run, entries):
             results[i] = out
             if confidences is not None:
@@ -644,25 +660,137 @@ def solve_fleet(
     ``(all_assignments, all_topk, not_best_count, n_spans,
     per_span_candidates, cnt_unassigned)``.
     """
-    # the fused path shards any mesh size (rows pad to a multiple); the
-    # per-service fallback solver requires a power-of-two mesh, so a
-    # non-pow2 mesh degrades fallback items to single-device rather than
-    # crashing the whole mixed solve on WeaverTPU's assert
-    n_mesh = int(mesh.devices.size) if mesh is not None else 1
-    fallback_mesh = mesh if n_mesh & (n_mesh - 1) == 0 else None
-    precision = validate_precision(
-        precision if precision is not None else precision_from_env())
-    solver_kwargs = dict(max_window=max_window, epsilon=epsilon,
-                         n_sinkhorn=n_sinkhorn, n_sweeps=n_sweeps,
-                         sinkhorn_tol=sinkhorn_tol, mesh=fallback_mesh,
-                         precision=precision)
-    solver = WeaverTPU(all_spans, all_processes, **solver_kwargs)
-    results: List[Optional[Tuple]] = [None] * len(items)
     st = _as_stats(stats)
+    results: List[Optional[Tuple]] = [None] * len(items)
+    with _profile.stage(st, "solve", items=len(items)):
+        with _profile.stage(st, "setup"):
+            # the fused path shards any mesh size (rows pad to a
+            # multiple); the per-service fallback solver requires a
+            # power-of-two mesh, so a non-pow2 mesh degrades fallback
+            # items to single-device rather than crashing the whole
+            # mixed solve on WeaverTPU's assert
+            n_mesh = int(mesh.devices.size) if mesh is not None else 1
+            fallback_mesh = mesh if n_mesh & (n_mesh - 1) == 0 else None
+            precision = validate_precision(
+                precision if precision is not None
+                else precision_from_env())
+            solver_kwargs = dict(max_window=max_window, epsilon=epsilon,
+                                 n_sinkhorn=n_sinkhorn, n_sweeps=n_sweeps,
+                                 sinkhorn_tol=sinkhorn_tol,
+                                 mesh=fallback_mesh, precision=precision)
+            solver = WeaverTPU(all_spans, all_processes, **solver_kwargs)
+            hypers_common = _hypers_common(
+                epsilon, n_sinkhorn, n_sweeps, sinkhorn_tol, precision,
+                mesh, plan_cache)
+            # supervisor context: what the degradation ladder needs to
+            # route a failing singleton to the per-service host fallback,
+            # where it records quarantined item indices for the caller
+            # (the stream service dead-letters the owning windows from
+            # this list), and the caller's per-item confidence slots the
+            # decode fills
+            ctx = dict(all_spans=all_spans, all_processes=all_processes,
+                       solver_kwargs=solver_kwargs,
+                       quarantined=(quarantined if quarantined is not None
+                                    else []),
+                       confidences=confidences)
 
+        with _profile.stage(st, "plan-fit", "plan_fit_s", items=len(items)):
+            prepared, fallback_entries = _plan_fit(
+                items, solver, plan_cache, item_cells, max_window)
+        if fallback_entries:
+            _run_fallback(fallback_entries, results, all_spans,
+                          all_processes, solver_kwargs, st,
+                          confidences=confidences)
+        if not prepared:
+            return results  # type: ignore[return-value]
+
+        with _profile.stage(st, "window-plan", "pack_s",
+                            items=len(prepared)):
+            plans = [_window_plan(i, item, prep, max_window, item_cells)
+                     for i, item, prep in prepared]
+
+        with _profile.stage(st, "group", items=len(plans)):
+            specs, over_budget = _group_specs(
+                _shape_classes(plans), score_itemsize(precision), st)
+        for group in over_budget:
+            # padded group block would stress HBM: per-service
+            # dispatches. The counter accumulates — a mixed workload can
+            # trip the budget on several groups and the ledger must say
+            # how many.
+            _run_fallback([(p[0], p[1]) for p in group], results,
+                          all_spans, all_processes, solver_kwargs, st,
+                          confidences=confidences)
+            st.add("fleet_fallback_budget", 1.0)
+        if not specs:
+            return results  # type: ignore[return-value]
+
+        from traceweaver_tpu.runtime.jax_cache import (
+            compile_counters,
+            counters_delta,
+        )
+
+        # recompiles are the shape-class regression signal: a warm
+        # steady state dispatches with zero compiles, so any nonzero
+        # delta here is a new program variant (bench surfaces these per
+        # run). Snapshotted around the WHOLE dispatch phase — per-dispatch
+        # deltas would double count under the pipeline's concurrent flows.
+        counters_before = compile_counters()
+        # mesh dispatches carry cross-replica collectives (the sweep's
+        # global convergence reduce, the fused refit's cross-shard
+        # gather), and XLA's rendezvous matches participants by
+        # per-device SUBMISSION order — two host threads racing their
+        # sharded launches onto the same devices interleave run ids and
+        # deadlock the whole mesh (observed live on the campaign's
+        # 2-device CPU probe). Sharded groups therefore always launch
+        # from the single serial flow; the pipeline's pack/dispatch
+        # overlap is a single-device optimization.
+        if _pipeline_on() and mesh is None:
+            _solve_groups_pipelined(specs, solver, results, st,
+                                    hypers_common, mesh, ctx)
+        else:
+            if mesh is not None and _pipeline_on() and len(specs) > 1:
+                st.add("mesh_serialized_groups", float(len(specs)))
+            _solve_groups_serial(specs, solver, results, st, hypers_common,
+                                 mesh, ctx)
+        for key, val in counters_delta(counters_before).items():
+            if val:
+                st.add(key, val)
+    return results  # type: ignore[return-value]
+
+
+def _hypers_common(epsilon, n_sinkhorn, n_sweeps, sinkhorn_tol, precision,
+                   mesh, plan_cache) -> Dict:
+    """The settings every dispatch group of one solve shares.
+
+    TW_CONF_DEVICE opts every fused dispatch into the confidence program
+    variant (quantized margin/entropy channels appended to the packed
+    block — packed_layout.py). A static jit arg, so the default False
+    keeps the dispatched programs byte-identical to the pre-quality
+    ones, and an enabled steady state recompiles nothing.
+
+    Device-resident span columns (TW_DEVCOLS, ops/devcols.py): window
+    tensors become on-device gathers from per-service HBM rings, with
+    only index arrays + skip/force tensors shipped per dispatch. Rides
+    the columnar host path's SpanArray columns, single-device only (the
+    mesh path re-places host tensors per shard); the flag travels here
+    so the supervisor's bisect re-packs inherit it."""
+    return dict(epsilon=epsilon, n_sinkhorn=n_sinkhorn, n_sweeps=n_sweeps,
+                sinkhorn_tol=sinkhorn_tol, precision=precision,
+                confidence=_quality.conf_device_enabled(),
+                devcols=(_devcols.devcols_enabled()
+                         and columnar_enabled() and mesh is None),
+                # host-only (like devcols): the dispatcher admits two-pass
+                # refit tables back into the plan cache; never forwarded
+                # to a device program
+                plan_cache=plan_cache)
+
+
+def _plan_fit(items, solver, plan_cache, item_cells, max_window):
+    """Each item's host plan (:func:`_prepare`, through the plan cache
+    when one is given). Returns the prepared ``(i, item, prep)`` triples
+    and the ``(i, item)`` pairs that need the per-service path."""
     prepared = []
     fallback_entries = []
-    t_plan = time.perf_counter()
     for i, item in enumerate(items):
         cached = (plan_cache.lookup(_plan_key(item))
                   if plan_cache is not None and item.warm_dists is None
@@ -680,60 +808,72 @@ def solve_fleet(
                 # later, so the bootstrap fit that just ran IS the plan
                 plan_cache.admit(_plan_key(item), prep["dists"])
             prepared.append((i, item, prep))
-    st.add("plan_fit_s", time.perf_counter() - t_plan)
-    if fallback_entries:
-        _run_fallback(fallback_entries, results, all_spans, all_processes,
-                      solver_kwargs, st, confidences=confidences)
-    if not prepared:
-        return results  # type: ignore[return-value]
+    return prepared, fallback_entries
 
-    # --- per-item window plan + shape class ------------------------------
-    t0 = time.perf_counter()
-    plans = []
-    for i, item, prep in prepared:
-        in_spans, out_eps = prep["in_spans"], prep["out_eps"]
-        in_cols, out_cols = prep["in_cols"], prep["out_cols"]
-        if in_cols is not None:
-            # columnar: windowing + ranges from the partition columns
-            windows = perfect_cut_windows_cols(in_cols, max_window)
-            out_starts_np = {ep: out_cols[ep].start for ep in out_eps}
-        else:
-            windows = perfect_cut_windows(in_spans, max_window)
-            out_starts_np = {
-                ep: np.array(sorted(float(s.start_mus)
-                                    for s in item.out_span_partitions[ep]))
-                for ep in out_eps
-            }
-        ranges = candidate_ranges(in_spans, windows, out_eps, out_starts_np,
-                                  in_cols=in_cols)
-        skip_caps = water_fill_skip_caps(
-            windows, ranges, len(in_spans),
-            [len(item.out_span_partitions[ep]) for ep in out_eps])
-        w_b = _bucket(max(hi - lo for lo, hi in windows))
-        m_b = _bucket(int((ranges[:, :, 1] - ranges[:, :, 0]).max(initial=1)))
-        if item_cells is not None:
-            item_cells[i] = (len(windows) * w_b * m_b
-                             * max(1, len(out_eps)) * prep["n_passes"])
-        plans.append((i, item, prep, windows, ranges, skip_caps, w_b, m_b))
-    st.add("pack_s", time.perf_counter() - t0)
 
-    # --- group services into dispatch shape classes ----------------------
-    # One fused program per class. Services with very different window
-    # geometry must not share one padded shape: hotel_load150's search
-    # (724 windows of 8x8x2) padded to its frontend's 32x32x3 pays 24x
-    # its own compute in padding. Small classes merge upward while the
-    # extra padded area stays under a budget that reflects the backend:
-    # on TPU padded cells are nearly-free VPU work and a saved dispatch
-    # is a saved host round trip (merge aggressively); on the CPU
-    # padded cells are real core-seconds (merge conservatively).
+def _window_plan(i, item, prep, max_window, item_cells):
+    """One prepared item's windows, candidate ranges, skip caps and
+    bucketed window geometry: the plan tuple the grouping and the packer
+    read (``(i, item, prep, windows, ranges, skip_caps, w_b, m_b)``)."""
+    in_spans, out_eps = prep["in_spans"], prep["out_eps"]
+    in_cols, out_cols = prep["in_cols"], prep["out_cols"]
+    if in_cols is not None:
+        # columnar: windowing + ranges from the partition columns
+        windows = perfect_cut_windows_cols(in_cols, max_window)
+        out_starts_np = {ep: out_cols[ep].start for ep in out_eps}
+    else:
+        windows = perfect_cut_windows(in_spans, max_window)
+        out_starts_np = {
+            ep: np.array(sorted(float(s.start_mus)
+                                for s in item.out_span_partitions[ep]))
+            for ep in out_eps
+        }
+    ranges = candidate_ranges(in_spans, windows, out_eps, out_starts_np,
+                              in_cols=in_cols)
+    skip_caps = water_fill_skip_caps(
+        windows, ranges, len(in_spans),
+        [len(item.out_span_partitions[ep]) for ep in out_eps])
+    w_b = _bucket(max(hi - lo for lo, hi in windows))
+    m_b = _bucket(int((ranges[:, :, 1] - ranges[:, :, 0]).max(initial=1)))
+    if item_cells is not None:
+        item_cells[i] = (len(windows) * w_b * m_b
+                         * max(1, len(out_eps)) * prep["n_passes"])
+    return (i, item, prep, windows, ranges, skip_caps, w_b, m_b)
+
+
+def _merge_budget() -> int:
+    """Padded cells two shape classes may add by merging.
+
+    Services with very different window geometry must not share one
+    padded shape: hotel_load150's search (724 windows of 8x8x2) padded
+    to its frontend's 32x32x3 pays 24x its own compute in padding. Small
+    classes merge upward while the extra padded area stays under a
+    budget that reflects the backend: on TPU padded cells are
+    nearly-free VPU work and a saved dispatch is a saved host round trip
+    (merge aggressively); on the CPU padded cells are real core-seconds
+    (merge conservatively). ``TW_FLEET_MERGE`` overrides it (0 = never
+    merge shape classes)."""
     merge_env = _knobs.get_int("TW_FLEET_MERGE")
     if merge_env is not None:
-        merge_budget = merge_env  # 0 = never merge shape classes
-    else:
-        import jax
+        return merge_env
+    import jax
 
-        merge_budget = (1 << 24) if jax.default_backend() == "tpu" \
-            else (1 << 20)
+    return (1 << 24) if jax.default_backend() == "tpu" else (1 << 20)
+
+
+def _shape_classes(plans) -> List[List]:
+    """Group window plans into dispatch shape classes: one fused program
+    per class.
+
+    The class key includes the endpoint-count bucket: an E=12 service
+    fused with an E=1 service would pay 12x endpoint padding on the
+    score block and E^2 growth on the refit rows — exactly the padding
+    class the merge budget exists to arbitrate, so E outliers start in
+    their own class and only merge if the padded cost approves. The pass
+    count splits classes too: single-pass (dynamism) and two-pass (fused
+    EM) services run different device programs and cannot share a
+    dispatch."""
+    merge_budget = _merge_budget()
 
     def shape_cost(group):
         w = max(p[6] for p in group)
@@ -741,14 +881,6 @@ def solve_fleet(
         e = max(len(p[2]["out_eps"]) for p in group)
         return sum(len(p[3]) for p in group) * w * m * e
 
-    # class key includes the endpoint-count bucket: an E=12 service fused
-    # with an E=1 service would pay 12x endpoint padding on the score
-    # block and E^2 growth on the refit rows — exactly the padding class
-    # the merge budget exists to arbitrate, so E outliers must start in
-    # their own class and only merge if shape_cost approves. The pass
-    # count splits classes too: single-pass (dynamism) and two-pass
-    # (fused EM) services run different device programs and cannot share
-    # a dispatch.
     classes: Dict[Tuple[int, int, int, int], List] = {}
     for plan in plans:
         e_b = _bucket(len(plan[2]["out_eps"]), minimum=1)
@@ -770,50 +902,20 @@ def solve_fleet(
         carry = []
     if carry:
         groups.append(carry)
+    return groups
 
-    # --- budget + dispatch per group -------------------------------------
-    # TW_CONF_DEVICE opts every fused dispatch into the confidence
-    # program variant (quantized margin/entropy channels appended to the
-    # packed block — packed_layout.py). A static jit arg, so the default
-    # False keeps the dispatched programs byte-identical to the
-    # pre-quality ones, and an enabled steady state recompiles nothing.
-    conf_device = _quality.conf_device_enabled()
-    # device-resident span columns (TW_DEVCOLS, ops/devcols.py): window
-    # tensors become on-device gathers from per-service HBM rings, with
-    # only index arrays + skip/force tensors shipped per dispatch. Rides
-    # the columnar host path's SpanArray columns, single-device only
-    # (the mesh path re-places host tensors per shard); the flag travels
-    # in hypers_common so the supervisor's bisect re-packs inherit it.
-    hypers_common = dict(epsilon=epsilon, n_sinkhorn=n_sinkhorn,
-                         n_sweeps=n_sweeps, sinkhorn_tol=sinkhorn_tol,
-                         precision=precision, confidence=conf_device,
-                         devcols=(_devcols.devcols_enabled()
-                                  and columnar_enabled() and mesh is None),
-                         # host-only (like devcols): the dispatcher admits
-                         # two-pass refit tables back into the plan cache;
-                         # never forwarded to a device program
-                         plan_cache=plan_cache)
-    itemsize = score_itemsize(precision)
-    # supervisor context: what the degradation ladder needs to route a
-    # failing singleton to the per-service host fallback, where it
-    # records quarantined item indices for the caller (the stream service
-    # dead-letters the owning windows from this list), and the caller's
-    # per-item confidence slots the decode fills
-    ctx = dict(all_spans=all_spans, all_processes=all_processes,
-               solver_kwargs=solver_kwargs,
-               quarantined=quarantined if quarantined is not None else [],
-               confidences=confidences)
+
+def _group_specs(groups, itemsize: int, st: "_Stats"):
+    """Padded geometry and byte cost of each group. Returns the specs to
+    dispatch, each indexed in dispatch order (the ``group`` argument of
+    its stage spans), and the groups over the live-dispatch budget,
+    which go to the per-service path."""
     specs: List[_GroupSpec] = []
+    over_budget: List[List] = []
     for group in groups:
-        spec = _make_spec(group, itemsize)
+        spec = _make_spec(group, itemsize, index=len(specs))
         if spec.cost > _fleet_budget_bytes():
-            # padded group block would stress HBM: per-service dispatches.
-            # The counter accumulates — a mixed workload can trip the
-            # budget on several groups and the ledger must say how many.
-            _run_fallback([(p[0], p[1]) for p in group], results,
-                          all_spans, all_processes, solver_kwargs, st,
-                          confidences=confidences)
-            st.add("fleet_fallback_budget", 1.0)
+            over_budget.append(group)
             continue
         # depth-limit observability (bytes): the largest single admission
         # and the total the budget must amortize (budget < total => the
@@ -821,49 +923,23 @@ def solve_fleet(
         st.record_max("fleet_group_cost_max", float(spec.cost))
         st.add("fleet_group_cost_total", float(spec.cost))
         specs.append(spec)
-    if not specs:
-        return results  # type: ignore[return-value]
-
-    from traceweaver_tpu.runtime.jax_cache import compile_counters, counters_delta
-
-    # recompiles are the shape-class regression signal: a warm steady
-    # state dispatches with zero compiles, so any nonzero delta here is a
-    # new program variant (bench surfaces these per run). Snapshotted
-    # around the WHOLE dispatch phase — per-dispatch deltas would double
-    # count under the pipeline's concurrent flows.
-    counters_before = compile_counters()
-    # mesh dispatches carry cross-replica collectives (the sweep's global
-    # convergence reduce, the fused refit's cross-shard gather), and XLA's
-    # rendezvous matches participants by per-device SUBMISSION order —
-    # two host threads racing their sharded launches onto the same
-    # devices interleave run ids and deadlock the whole mesh (observed
-    # live on the campaign's 2-device CPU probe). Sharded groups
-    # therefore always launch from the single serial flow; the pipeline's
-    # pack/dispatch overlap is a single-device optimization.
-    if _pipeline_on() and mesh is None:
-        _solve_groups_pipelined(specs, solver, results, st, hypers_common,
-                                mesh, ctx)
-    else:
-        if mesh is not None and _pipeline_on() and len(specs) > 1:
-            st.add("mesh_serialized_groups", float(len(specs)))
-        _solve_groups_serial(specs, solver, results, st, hypers_common,
-                             mesh, ctx)
-    for key, val in counters_delta(counters_before).items():
-        if val:
-            st.add(key, val)
-    return results  # type: ignore[return-value]
+    return specs, over_budget
 
 
 class _GroupSpec:
     """One shape-class dispatch group plus its padded geometry and budget
     cost (live BYTES while its blocks are in flight, dtype-aware at the
     score precision — the unit the pipeline depth limit is denominated
-    in)."""
+    in). ``span_args`` are the arguments every stage span of the group
+    carries (``group`` index in dispatch order, ``items``, ``windows``,
+    ``spans``), so its pack, dispatch, fetches and decode join in a
+    profiler trace."""
 
     __slots__ = ("group", "W_pad", "M_pad", "E_pad", "bmax", "n_passes",
-                 "cost")
+                 "cost", "span_args")
 
-    def __init__(self, group, W_pad, M_pad, E_pad, bmax, n_passes, cost):
+    def __init__(self, group, W_pad, M_pad, E_pad, bmax, n_passes, cost,
+                 span_args):
         self.group = group
         self.W_pad = W_pad
         self.M_pad = M_pad
@@ -871,14 +947,17 @@ class _GroupSpec:
         self.bmax = bmax
         self.n_passes = n_passes
         self.cost = cost
+        self.span_args = span_args
 
 
-def _make_spec(group: List, itemsize: int) -> _GroupSpec:
+def _make_spec(group: List, itemsize: int, index: int) -> _GroupSpec:
     """Padded geometry + byte cost of one dispatch group. One definition
     shared by the initial shape-class grouping and the supervisor's
     bisection rung, so a bisected half is budgeted and padded by exactly
     the rules the full group was (per-plan W/M buckets are already
-    powers of two, so halves cannot mint unbucketed shapes)."""
+    powers of two, so halves cannot mint unbucketed shapes). ``index``
+    is the group's place in dispatch order; a bisected half keeps its
+    whole group's."""
     W_pad = max(p[6] for p in group)
     M_pad = max(p[7] for p in group)
     E_pad = max(len(p[2]["out_eps"]) for p in group)
@@ -896,7 +975,10 @@ def _make_spec(group: List, itemsize: int) -> _GroupSpec:
     # precision's itemsize (bf16 = half), the refit samples always
     # f32 (GMM EM stays full-precision)
     cost = score_elems * itemsize + refit_elems * 4
-    return _GroupSpec(group, W_pad, M_pad, E_pad, bmax, n_passes, cost)
+    span_args = dict(group=index, items=P, windows=n_windows_total,
+                     spans=sum(p[2]["n_in"] for p in group))
+    return _GroupSpec(group, W_pad, M_pad, E_pad, bmax, n_passes, cost,
+                      span_args)
 
 
 # ---------------------------------------------------------------------------
@@ -975,7 +1057,7 @@ def _degrade_group(err, solver, pg, spec, results, st, hypers_common, mesh,
             time.sleep(backoff * (2 ** attempt))
         st.add("fault_retries")
         st.note("fault_ladder", "retry")
-        _trace_stage(rung_keys, "retry", _selftrace.now_us())
+        _trace_rung(rung_keys, "retry")
         try:
             _attempt_group(solver, pg, spec, results, st, hypers_common,
                            mesh, ctx)
@@ -991,11 +1073,12 @@ def _degrade_group(err, solver, pg, spec, results, st, hypers_common, mesh,
         # bisect: isolate the offender instead of failing the class
         st.add("fault_bisections")
         st.note("fault_ladder", "bisect")
-        _trace_stage(rung_keys, "bisect", _selftrace.now_us())
+        _trace_rung(rung_keys, "bisect")
         mid = len(spec.group) // 2
         itemsize = score_itemsize(hypers_common.get("precision", "f32"))
         for half in (spec.group[:mid], spec.group[mid:]):
-            half_spec = _make_spec(half, itemsize)
+            half_spec = _make_spec(half, itemsize,
+                                   spec.span_args["group"])
             half_pg = _pack_group(half_spec, hypers_common, st)
             try:
                 _attempt_group(solver, half_pg, half_spec, results, st,
@@ -1008,7 +1091,7 @@ def _degrade_group(err, solver, pg, spec, results, st, hypers_common, mesh,
     # --- singleton rungs -------------------------------------------------
     st.add("fault_xla_fallbacks")
     st.note("fault_ladder", "xla")
-    _trace_stage(rung_keys, "xla-fallback", _selftrace.now_us())
+    _trace_rung(rung_keys, "xla-fallback")
     try:
         _attempt_group(solver, pg, spec, results, st,
                        {**hypers_common, "pallas": False}, mesh, ctx)
@@ -1021,7 +1104,7 @@ def _degrade_group(err, solver, pg, spec, results, st, hypers_common, mesh,
     plan = spec.group[0]
     st.add("fault_host_fallbacks")
     st.note("fault_ladder", "host")
-    _trace_stage(rung_keys, "host-fallback", _selftrace.now_us())
+    _trace_rung(rung_keys, "host-fallback")
     try:
         _fault_check("host", st)
         _run_fallback([(plan[0], plan[1])], results, ctx["all_spans"],
@@ -1036,7 +1119,7 @@ def _degrade_group(err, solver, pg, spec, results, st, hypers_common, mesh,
 
     st.add("fault_quarantined")
     st.note("fault_ladder", "quarantine")
-    _trace_stage(rung_keys, "quarantine", _selftrace.now_us())
+    _trace_rung(rung_keys, "quarantine")
     results[plan[0]] = _quarantine_result(plan)
     if ctx.get("confidences") is not None:
         # a quarantined window's reconstruction is all-NA: zero
@@ -1265,8 +1348,50 @@ def _resolve_group_devcols(group, st: _Stats):
 
 def _pack_group(spec: _GroupSpec, hypers_common, st: _Stats):
     """Host packing of one shape-class group (pure NumPy — safe on the
-    pipeline's pack thread): concatenated window tensors, stacked param
-    tables, the refit row maps, and the analytic op accounting.
+    pipeline's pack thread), timed as the ``pack`` stage, then the
+    group's dispatch ledger and analytic op accounting."""
+    # self-trace context for this group: every distinct window key whose
+    # item rides this dispatch (carried on the ticket — the decode
+    # worker that finishes the flow stamps the same keys)
+    trace_keys = sorted({item.trace_key for _, item, *_ in spec.group
+                         if item.trace_key is not None})
+    with _profile.stage(st, "pack", "pack_s", trace_keys,
+                        **spec.span_args):
+        pg = _pack_tensors(spec, hypers_common, st)
+    pg["trace_keys"] = trace_keys
+    st.add("fleet_dispatches", 1.0)
+    st.add("fleet_services", float(len(pg["per_item_pack"])))
+    if st.d is not None:
+        # analytic op accounting (UPPER BOUND — sweep and Sinkhorn loops
+        # exit early on convergence), same model as WeaverTPU._solve_once
+        n_sweeps = hypers_common["n_sweeps"]
+        n_sinkhorn = hypers_common["n_sinkhorn"]
+        itemsize = score_itemsize(hypers_common.get("precision", "f32"))
+        K = pg["params"]["in_wt"].shape[2]
+        W_pad, E_pad = spec.W_pad, spec.E_pad
+        cells = (pg["n_rows"] * E_pad * W_pad * spec.M_pad
+                 * n_sweeps * spec.n_passes)
+        st.add("flops_est", cells * (
+            8.0 * K * (min(pg["max_preds"], E_pad)
+                       + min(pg["max_succs"], E_pad) + 2)
+            + 6.0 * 2 * n_sinkhorn
+            + 8.0 * max(1, W_pad.bit_length())
+        ))
+        # score-block HBM traffic at the configured precision's itemsize
+        # (bf16 halves it); the Pallas term keeps the f32 plan write
+        st.add("bytes_est_xla", cells * float(itemsize) * 2 * n_sinkhorn)
+        st.add("bytes_est_pallas", cells * (float(itemsize) + 2 * 4.0))
+        if spec.n_passes == 2:
+            # counts fused EM dispatches (the grouping may produce several)
+            st.add("fused_em_applied", 1.0)
+        else:
+            st.add("fleet_dynamism_dispatches", 1.0)
+    return pg
+
+
+def _pack_tensors(spec: _GroupSpec, hypers_common, st: _Stats):
+    """The packed group: concatenated window tensors, stacked param
+    tables, the refit row maps.
 
     Under ``TW_DEVCOLS`` the pack thread feeds INDEX ARRAYS, not
     tensors: each item packs through
@@ -1278,9 +1403,6 @@ def _pack_group(spec: _GroupSpec, hypers_common, st: _Stats):
     (``devcols_fallbacks``)."""
     group = spec.group
     W_pad, M_pad, E_pad, bmax = spec.W_pad, spec.M_pad, spec.E_pad, spec.bmax
-    n_passes = spec.n_passes
-    t0 = time.perf_counter()
-    w0 = _selftrace.now_us()
     arrays_cat: Dict[str, List[np.ndarray]] = {}
     param_rows: Dict[str, List[np.ndarray]] = {k: [] for k in _TABLE_KEYS}
     per_item_pack = []
@@ -1371,44 +1493,12 @@ def _pack_group(spec: _GroupSpec, hypers_common, st: _Stats):
         window_rows[p, :n_w] = np.arange(row0, row0 + n_w, dtype=np.int32)
         window_valid[p, :n_w] = True
         row0 += n_w
-    # self-trace context for this group: every distinct window key whose
-    # item rides this dispatch (carried on the ticket below — the decode
-    # worker that finishes the flow stamps the same keys)
-    trace_keys = sorted({item.trace_key for _, item, *_ in group
-                         if item.trace_key is not None})
-    _trace_stage(trace_keys, "pack", w0)
-    st.add("pack_s", time.perf_counter() - t0)
-    st.add("fleet_dispatches", 1.0)
-    st.add("fleet_services", float(len(per_item_pack)))
-    if st.d is not None:
-        # analytic op accounting (UPPER BOUND — sweep and Sinkhorn loops
-        # exit early on convergence), same model as WeaverTPU._solve_once
-        n_sweeps = hypers_common["n_sweeps"]
-        n_sinkhorn = hypers_common["n_sinkhorn"]
-        itemsize = score_itemsize(hypers_common.get("precision", "f32"))
-        K = params["in_wt"].shape[2]
-        cells = (n_windows_total * E_pad * W_pad * M_pad
-                 * n_sweeps * n_passes)
-        st.add("flops_est", cells * (
-            8.0 * K * (min(_mp, E_pad) + min(_ms, E_pad) + 2)
-            + 6.0 * 2 * n_sinkhorn
-            + 8.0 * max(1, W_pad.bit_length())
-        ))
-        # score-block HBM traffic at the configured precision's itemsize
-        # (bf16 halves it); the Pallas term keeps the f32 plan write
-        st.add("bytes_est_xla", cells * float(itemsize) * 2 * n_sinkhorn)
-        st.add("bytes_est_pallas", cells * (float(itemsize) + 2 * 4.0))
-        if n_passes == 2:
-            # counts fused EM dispatches (the grouping may produce several)
-            st.add("fused_em_applied", 1.0)
-        else:
-            st.add("fleet_dynamism_dispatches", 1.0)
     return dict(batch=batch, params=params, pidx=pidx,
                 window_rows=window_rows, window_valid=window_valid,
                 per_item_pack=per_item_pack, max_preds=_mp, max_succs=_ms,
                 tenant_table=tenant_table,
                 tenant_col=np.asarray(tenant_idx, dtype=np.int32),
-                trace_keys=trace_keys, n_rows=n_windows_total,
+                n_rows=n_windows_total,
                 devcols_items=devcols_items if use_devcols else None)
 
 
@@ -1517,9 +1607,8 @@ def _dispatch_packed(pg, spec: _GroupSpec, st: _Stats, hypers_common,
                 [tenant_col,
                  np.full(batch["in_start"].shape[0] - true_b, -1,
                          dtype=tenant_col.dtype)])
-    t0 = time.perf_counter()
-    w0 = _selftrace.now_us()
     trace_keys = pg.get("trace_keys") or ()
+    span_args = spec.span_args
     # this flow's blocking time (compacted intermediate fetches), so
     # dispatch_s below stays pure launch/host time even when several
     # flows bill wait_s to the shared dict concurrently
@@ -1530,23 +1619,24 @@ def _dispatch_packed(pg, spec: _GroupSpec, st: _Stats, hypers_common,
     # dispatch accounting closes — decode work, not launch time)
     plan_cache = hypers_common.get("plan_cache")
     refit_sink = [] if (plan_cache is not None and n_passes == 2) else None
-    if use_compact:
-        out = _solve_group_compacted(
-            batch, pidx, params, _tables_of(params), window_rows,
-            window_valid, n_passes, n_sweeps, warm, hypers, st,
-            mesh=mesh, flow_wait=flow_wait,
-            tenant_col=tenant_col, tenant_table=tenant_table,
-            trace_keys=trace_keys, assemble=assemble,
-            refit_sink=refit_sink)
-    elif assemble is not None:
-        # device-resident path: window tensors are assembled on device
-        # from the rings; only index arrays + skip/force shipped. The
-        # batch-row axis pads to a power of two with all-invalid rows
-        # (decoded by nobody, converge instantly) so the continuous-
-        # batching scheduler's varying admission counts dispatch against
-        # a BOUNDED shape lattice — steady state mints zero compiles
-        # (tests/test_continuous.py pins it)
-        with _profile.annotate("tw:fleet:dispatch"):
+    with _profile.stage(st, "dispatch", "dispatch_s", trace_keys,
+                        **span_args) as timed:
+        if use_compact:
+            out = _solve_group_compacted(
+                batch, pidx, params, _tables_of(params), window_rows,
+                window_valid, n_passes, n_sweeps, warm, hypers, st,
+                mesh=mesh, flow_wait=flow_wait,
+                tenant_col=tenant_col, tenant_table=tenant_table,
+                trace_keys=trace_keys, assemble=assemble,
+                refit_sink=refit_sink, span_args=span_args)
+        elif assemble is not None:
+            # device-resident path: window tensors are assembled on device
+            # from the rings; only index arrays + skip/force shipped. The
+            # batch-row axis pads to a power of two with all-invalid rows
+            # (decoded by nobody, converge instantly) so the continuous-
+            # batching scheduler's varying admission counts dispatch against
+            # a BOUNDED shape lattice — steady state mints zero compiles
+            # (tests/test_continuous.py pins it)
             pad_b = _bucket(pg["n_rows"], minimum=1) - pg["n_rows"]
             common = assemble(None, pad_b) + (_pad_pidx(pidx, pad_b),)
             if n_passes == 2:
@@ -1565,8 +1655,7 @@ def _dispatch_packed(pg, spec: _GroupSpec, st: _Stats, hypers_common,
                     *common, *_tables_of(params), n_sweeps=n_sweeps,
                     **hypers,
                 )
-    else:
-        with _profile.annotate("tw:fleet:dispatch"):
+        else:
             if mesh is not None:
                 import jax
                 from jax.sharding import NamedSharding, PartitionSpec
@@ -1605,10 +1694,8 @@ def _dispatch_packed(pg, spec: _GroupSpec, st: _Stats, hypers_common,
                     *common, *_tables_of(params), n_sweeps=n_sweeps,
                     **hypers,
                 )
-    dispatch_s = time.perf_counter() - t0 - flow_wait[0]
-    st.add("dispatch_s", dispatch_s)
-    _OBS_DISPATCH_S.observe(dispatch_s)
-    _trace_stage(trace_keys, "dispatch", w0)
+        timed.less = flow_wait[0]
+    _OBS_DISPATCH_S.observe(timed.seconds)
     _copy_async(out)
     if refit_sink:
         # two-pass admission: decode the refit tables the device already
@@ -1618,20 +1705,21 @@ def _dispatch_packed(pg, spec: _GroupSpec, st: _Stats, hypers_common,
         # host planning riding the flow worker, not launch time. Under
         # the pipeline it overlaps the next group's pack/dispatch — the
         # overlapped-residual-planning contract.
-        t_admit = time.perf_counter()
-        tables9 = tuple(np.asarray(t) for t in refit_sink[0])
         from traceweaver_tpu.algorithms.weaver_tpu import dists_from_tables
-        for p, (_, item, prep, _, _) in enumerate(pg["per_item_pack"]):
-            if item.warm_dists is not None:
-                continue
-            plan_cache.admit(
-                _plan_key(item),
-                dists_from_tables(prep["out_eps"], prep["in_ep"],
-                                  *(t[p] for t in tables9)))
-        st.add("plan_fit_s", time.perf_counter() - t_admit)
+
+        with _profile.stage(st, "plan-fit", "plan_fit_s", **span_args):
+            tables9 = tuple(np.asarray(t) for t in refit_sink[0])
+            for p, (_, item, prep, _, _) in enumerate(pg["per_item_pack"]):
+                if item.warm_dists is not None:
+                    continue
+                plan_cache.admit(
+                    _plan_key(item),
+                    dists_from_tables(prep["out_eps"], prep["in_ep"],
+                                      *(t[p] for t in tables9)))
     # the decode ticket carries the program-variant flag so the decode
     # worker splits the packed channels by the layout the dispatch used
-    return pg["per_item_pack"], out, hypers.get("confidence", False)
+    return (pg["per_item_pack"], out, hypers.get("confidence", False),
+            span_args)
 
 
 def _tables_of(params: Dict) -> Tuple:
@@ -1749,7 +1837,8 @@ def _make_assembler(dc_items: List[Dict], batch: Dict, st: _Stats):
 
 def _compacted_pass(batch, pidx, tables, n_sweeps, warm, hypers, stats,
                     mesh=None, flow_wait=None, tenant_col=None,
-                    tenant_table=None, trace_keys=(), assemble=None):
+                    tenant_table=None, trace_keys=(), assemble=None,
+                    span_args=None):
     """One solve pass as warm dispatch + compacted full redispatch.
 
     Returns the packed [B, E, W, 3+topk] output as a host array,
@@ -1771,8 +1860,10 @@ def _compacted_pass(batch, pidx, tables, n_sweeps, warm, hypers, stats,
     multi-chip runs compact too — each device receives a power-of-two
     row count and the total divides evenly across the mesh. Per-window
     outputs are sharding-independent (the solve is a vmap over windows),
-    so 1- and N-device compacted runs stay identical."""
+    so 1- and N-device compacted runs stay identical. ``span_args`` are
+    the group's stage-span arguments."""
     st = _as_stats(stats)
+    span_args = span_args or {}
     n_shards = int(mesh.devices.size) if mesh is not None else 1
 
     def place(arrs, pidx_np):
@@ -1798,7 +1889,7 @@ def _compacted_pass(batch, pidx, tables, n_sweeps, warm, hypers, stats,
         tables_dev = tuple(jax.device_put(np.asarray(t), rep)
                            for t in tables)
 
-    with _profile.annotate("tw:fleet:warm-dispatch"):
+    with _profile.stage(st, "warm-dispatch", **span_args):
         if assemble is not None:
             # pow2 batch-row padding (all-invalid rows): bounded shape
             # lattice under continuous batching, see _dispatch_packed
@@ -1814,16 +1905,14 @@ def _compacted_pass(batch, pidx, tables, n_sweeps, warm, hypers, stats,
     # the big warm block starts its D2H NOW — it overlaps the flag fetch,
     # the host gather, and the redispatch's device execution below
     _copy_async(out_warm)
-    w0 = _selftrace.now_us()
-    with _profile.annotate("tw:fleet:flag-fetch"):
-        converged = _fetch_flags(flags, st, flow_wait,
-                                 mesh=mesh).astype(bool)
+    converged = _fetch_flags(flags, st, flow_wait, mesh=mesh,
+                             trace_keys=trace_keys,
+                             span_args=span_args).astype(bool)
     if assemble is not None:
         # drop the pow2 padding rows: all-invalid windows converge by
         # construction and must not inflate the compaction ledger (or
         # reach the redispatch row gather)
         converged = converged[:assemble.n_rows]
-    _trace_stage(trace_keys, "compact-fetch", w0)
     active = np.flatnonzero(~converged)
     st.add("compact_windows_total", float(converged.shape[0]))
     st.add("compact_windows_redispatched", float(active.size))
@@ -1838,7 +1927,7 @@ def _compacted_pass(batch, pidx, tables, n_sweeps, warm, hypers, stats,
                 st.bucket("tenant_windows_redispatched",
                           tenant_table[int(t_i)], float(c))
     if active.size == 0:
-        return _fetch(out_warm, st, flow_wait)
+        return _fetch(out_warm, st, flow_wait, span_args=span_args)
 
     from traceweaver_tpu.parallel.mesh import bucket_rows_per_shard
 
@@ -1866,18 +1955,18 @@ def _compacted_pass(batch, pidx, tables, n_sweeps, warm, hypers, stats,
                     [a, np.zeros((pad,) + a.shape[1:], dtype=a.dtype)])
             gathered[k] = a
         redispatch_common = place(gathered, pidx_active)
-    w0 = _selftrace.now_us()
-    with _profile.annotate("tw:fleet:redispatch"):
+    with _profile.stage(st, "redispatch", trace_keys=trace_keys,
+                        **span_args):
         _note_aot(st, _aot.note_fleet(
             "solve_windows_fleet", redispatch_common, tables_dev,
             n_sweeps, hypers, mesh=mesh))
         out_full, _ = solve_windows_fleet(
             *redispatch_common, *tables_dev,
             n_sweeps=n_sweeps, **hypers)
-    _trace_stage(trace_keys, "redispatch", w0)
     _copy_async(out_full)
-    out = _fetch(out_warm, st, flow_wait).copy()
-    out[active] = _fetch(out_full, st, flow_wait)[:active.size]
+    out = _fetch(out_warm, st, flow_wait, span_args=span_args).copy()
+    out[active] = _fetch(out_full, st, flow_wait,
+                         span_args=span_args)[:active.size]
     return out
 
 
@@ -1885,7 +1974,8 @@ def _solve_group_compacted(batch, pidx, params, tables, window_rows,
                            window_valid, n_passes, n_sweeps, warm, hypers,
                            stats, mesh=None, flow_wait=None,
                            tenant_col=None, tenant_table=None,
-                           trace_keys=(), assemble=None, refit_sink=None):
+                           trace_keys=(), assemble=None, refit_sink=None,
+                           span_args=None):
     """Compacted replacement for one fused group dispatch: per-pass
     warm/redispatch compaction, with the two-pass EM's on-device refit as
     its own dispatch between the passes (same refit program
@@ -1900,7 +1990,8 @@ def _solve_group_compacted(batch, pidx, params, tables, window_rows,
     out0 = _compacted_pass(batch, pidx, tables, n_sweeps, warm, hypers, st,
                            mesh=mesh, flow_wait=flow_wait,
                            tenant_col=tenant_col, tenant_table=tenant_table,
-                           trace_keys=trace_keys, assemble=assemble)
+                           trace_keys=trace_keys, assemble=assemble,
+                           span_args=span_args)
     if n_passes == 1:
         return out0
     if assemble is not None:
@@ -1934,7 +2025,8 @@ def _solve_group_compacted(batch, pidx, params, tables, window_rows,
         # replicated device_put starts from committed-free arrays — a
         # LEDGERED fetch (the refit tables are small, but the block on
         # the refit program's execution is real device wait)
-        new_tables = tuple(_fetch(t, st, flow_wait) for t in new_tables)
+        new_tables = tuple(_fetch(t, st, flow_wait, span_args=span_args)
+                           for t in new_tables)
     if refit_sink is not None:
         # plan-cache admission material: the dispatcher decodes these
         # AFTER its dispatch accounting closes (device handles are fine —
@@ -1944,71 +2036,82 @@ def _solve_group_compacted(batch, pidx, params, tables, window_rows,
                            n_sweeps, warm, hypers, st, mesh=mesh,
                            flow_wait=flow_wait,
                            tenant_col=tenant_col, tenant_table=tenant_table,
-                           trace_keys=trace_keys, assemble=assemble)
+                           trace_keys=trace_keys, assemble=assemble,
+                           span_args=span_args)
 
 
 def _decode_group(solver, pend, results, stats, ctx=None):
-    """Fetch one group's packed output and decode it per service.
+    """Fetch one group's packed output and decode it per service (the
+    ``decode`` stage).
 
     Safe on a pipeline decode worker: every write lands in that group's
     own input-order ``results`` slots (and its own ``confidences``
     slots) and all counter updates go through the lock-guarded
     accumulator."""
     st = _as_stats(stats)
-    per_item_pack, out, conf_device = pend
+    per_item_pack, out, conf_device, span_args = pend
     confidences = (ctx or {}).get("confidences")
-    conf_on = confidences is not None and _quality.conf_enabled()
+    if not _quality.conf_enabled():
+        confidences = None
     # the compacted flow already fetched + merged on the host; the
     # single-dispatch flows hand over an async device handle
-    o = out if isinstance(out, np.ndarray) else _fetch(out, st)
+    o = (out if isinstance(out, np.ndarray)
+         else _fetch(out, st, span_args=span_args))
+    trace_keys = sorted({item.trace_key for _, item, *_ in per_item_pack
+                         if item.trace_key is not None})
+    with _profile.stage(st, "decode", "decode_s", trace_keys, **span_args):
+        row = 0
+        for i, item, prep, packed, n_w in per_item_pack:
+            results[i] = _decode_item(solver, item, prep, packed,
+                                      o[row:row + n_w], conf_device,
+                                      confidences, i, st)
+            row += n_w
 
-    t0 = time.perf_counter()
-    w0 = _selftrace.now_us()
-    row = 0
-    for i, item, prep, packed, n_w in per_item_pack:
-        rows = o[row:row + n_w]
-        row += n_w
-        if item.tenant is not None:
-            # tenancy column, decode end: packed == decoded per tenant is
-            # the conservation check the serve tests assert from stats
-            st.bucket("tenant_windows_decoded", item.tenant, float(n_w))
-        ch = _layout.split_packed(rows, confidence=conf_device)
-        assign = ch["assign"]
-        not_best = ch["not_best"]
-        feas = ch["feas"]
-        topk_cols = ch["topk_cols"]
-        out_eps = prep["out_eps"]
-        in_ids = (prep["in_cols"].ids.tolist()
-                  if prep.get("in_cols") is not None
-                  else [s.GetId() for s in prep["in_spans"]])
-        n_in = prep["n_in"]
 
-        all_assignments = {ep: {} for ep in out_eps}
-        all_topk = {ep: {} for ep in out_eps}
-        solver._decode(packed, assign, topk_cols, all_assignments, all_topk)
-        span_not_best = np.zeros(n_in, dtype=bool)
-        span_cands = np.ones(n_in, dtype=np.int64)
-        scatter_window_span_stats(packed.windows, not_best, feas,
-                                  span_not_best, span_cands)
-        if conf_on:
-            # per-span quality reductions from the SAME fetched block —
-            # no extra transfer, no device change (obs/quality.py); the
-            # slot write is race-free like the results slot (input-order,
-            # one writer per item)
-            arrs = _quality.span_confidence_arrays(
-                packed.windows, rows, n_in, device=conf_device)
-            confidences[i] = _quality.confidence_records(in_ids, arrs)
-        solver._resolve_cross_window_duplicates(
-            all_assignments, all_topk, in_ids, prep["skip_budget"])
-        cnt_unassigned = sum(
-            1 for in_id in in_ids
-            if any(all_assignments[ep][in_id] == NA for ep in out_eps)
-        )
-        results[i] = (
-            all_assignments, all_topk, int(span_not_best.sum()), n_in,
-            {in_ids[j]: int(span_cands[j]) for j in range(n_in)},
-            cnt_unassigned,
-        )
-    _trace_stage(sorted({item.trace_key for _, item, *_ in per_item_pack
-                         if item.trace_key is not None}), "decode", w0)
-    st.add("decode_s", time.perf_counter() - t0)
+def _decode_item(solver, item, prep, packed, rows, conf_device,
+                 confidences, i, st: _Stats) -> Tuple:
+    """One service's FindAssignments 6-tuple from its rows of the packed
+    block; its per-span quality records go to ``confidences[i]`` when
+    the caller keeps them."""
+    n_w = rows.shape[0]
+    if item.tenant is not None:
+        # tenancy column, decode end: packed == decoded per tenant is
+        # the conservation check the serve tests assert from stats
+        st.bucket("tenant_windows_decoded", item.tenant, float(n_w))
+    ch = _layout.split_packed(rows, confidence=conf_device)
+    assign = ch["assign"]
+    not_best = ch["not_best"]
+    feas = ch["feas"]
+    topk_cols = ch["topk_cols"]
+    out_eps = prep["out_eps"]
+    in_ids = (prep["in_cols"].ids.tolist()
+              if prep.get("in_cols") is not None
+              else [s.GetId() for s in prep["in_spans"]])
+    n_in = prep["n_in"]
+
+    all_assignments = {ep: {} for ep in out_eps}
+    all_topk = {ep: {} for ep in out_eps}
+    solver._decode(packed, assign, topk_cols, all_assignments, all_topk)
+    span_not_best = np.zeros(n_in, dtype=bool)
+    span_cands = np.ones(n_in, dtype=np.int64)
+    scatter_window_span_stats(packed.windows, not_best, feas,
+                              span_not_best, span_cands)
+    if confidences is not None:
+        # per-span quality reductions from the SAME fetched block —
+        # no extra transfer, no device change (obs/quality.py); the
+        # slot write is race-free like the results slot (input-order,
+        # one writer per item)
+        arrs = _quality.span_confidence_arrays(
+            packed.windows, rows, n_in, device=conf_device)
+        confidences[i] = _quality.confidence_records(in_ids, arrs)
+    solver._resolve_cross_window_duplicates(
+        all_assignments, all_topk, in_ids, prep["skip_budget"])
+    cnt_unassigned = sum(
+        1 for in_id in in_ids
+        if any(all_assignments[ep][in_id] == NA for ep in out_eps)
+    )
+    return (
+        all_assignments, all_topk, int(span_not_best.sum()), n_in,
+        {in_ids[j]: int(span_cands[j]) for j in range(n_in)},
+        cnt_unassigned,
+    )
