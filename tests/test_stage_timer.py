@@ -49,11 +49,12 @@ GROUP_STAGES = {"pack", "dispatch", "warm-dispatch", "flag-fetch",
                 "redispatch", "fetch", "decode"}
 #: the stats keys of a warm pipelined solve of ``_mixed_items`` (three
 #: shape classes kept apart), as the fleet billed them before the stage
-#: timer
+#: timer, and decode's count of contested out-spans
 STEADY_KEYS = {
     "bytes_est_pallas", "bytes_est_xla", "compact_windows_redispatched",
     "compact_windows_total", "d2h_bytes_fetched", "d2h_bytes_flags",
-    "d2h_flag_fetches", "decode_s", "devcols_fallbacks", "dispatch_s",
+    "d2h_flag_fetches", "decode_contested", "decode_s",
+    "devcols_fallbacks", "dispatch_s",
     "fleet_dispatches", "fleet_dynamism_dispatches",
     "fleet_group_cost_max", "fleet_group_cost_total", "fleet_services",
     "flops_est", "fused_em_applied", "h2d_bytes_shipped",

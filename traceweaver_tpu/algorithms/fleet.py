@@ -59,6 +59,7 @@ from traceweaver_tpu.algorithms.weaver_tpu import (
     scatter_window_span_stats,
     solve_em_fleet,
     solve_windows_fleet,
+    window_index,
 )
 from traceweaver_tpu.ops import devcols as _devcols
 from traceweaver_tpu.ops.pallas_sinkhorn import (
@@ -2089,12 +2090,14 @@ def _decode_item(solver, item, prep, packed, rows, conf_device,
               else [s.GetId() for s in prep["in_spans"]])
     n_in = prep["n_in"]
 
+    index = window_index(packed.windows)
     all_assignments = {ep: {} for ep in out_eps}
     all_topk = {ep: {} for ep in out_eps}
-    solver._decode(packed, assign, topk_cols, all_assignments, all_topk)
+    chosen_keys = solver._decode(packed, assign, topk_cols,
+                                 all_assignments, all_topk, index)
     span_not_best = np.zeros(n_in, dtype=bool)
     span_cands = np.ones(n_in, dtype=np.int64)
-    scatter_window_span_stats(packed.windows, not_best, feas,
+    scatter_window_span_stats(index, not_best, feas,
                               span_not_best, span_cands)
     if confidences is not None:
         # per-span quality reductions from the SAME fetched block —
@@ -2102,16 +2105,16 @@ def _decode_item(solver, item, prep, packed, rows, conf_device,
         # slot write is race-free like the results slot (input-order,
         # one writer per item)
         arrs = _quality.span_confidence_arrays(
-            packed.windows, rows, n_in, device=conf_device)
+            index, rows, n_in, device=conf_device)
         confidences[i] = _quality.confidence_records(in_ids, arrs)
-    solver._resolve_cross_window_duplicates(
-        all_assignments, all_topk, in_ids, prep["skip_budget"])
-    cnt_unassigned = sum(
-        1 for in_id in in_ids
-        if any(all_assignments[ep][in_id] == NA for ep in out_eps)
-    )
+    unassigned, n_contested = solver._resolve_contested(
+        packed, chosen_keys, index, in_ids, all_assignments, all_topk,
+        prep["skip_budget"])
+    # out-spans claimed by more than one window: how often the serial
+    # rule engages
+    st.add("decode_contested", float(n_contested))
     return (
         all_assignments, all_topk, int(span_not_best.sum()), n_in,
-        {in_ids[j]: int(span_cands[j]) for j in range(n_in)},
-        cnt_unassigned,
+        dict(zip(in_ids, span_cands.tolist())),
+        int(np.count_nonzero(unassigned)),
     )

@@ -41,6 +41,7 @@ import math
 import warnings
 from dataclasses import dataclass, field
 from functools import partial
+from itertools import chain
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -918,24 +919,39 @@ def perfect_cut_windows_cols(cols: SpanArray,
     return windows
 
 
-def scatter_window_span_stats(windows, not_best, feas,
+def window_index(windows) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """``(w_of, i_of, pos)`` of a packed window batch: for every incoming
+    span, in window order, its window row, its row inside that window,
+    and its position in the item's sorted incoming spans.
+
+    A few whole-array passes over the ``[lo, hi)`` pairs, however many
+    windows there are: hotel windows hold one or two spans each, so a
+    per-window ``np.arange`` costs more than the rest of decode. Every
+    per-span reduction of a batch reads this one index.
+    """
+    bounds = np.fromiter(chain.from_iterable(windows), np.int64,
+                         2 * len(windows)).reshape(-1, 2)
+    lens = bounds[:, 1] - bounds[:, 0]
+    w_of = np.repeat(np.arange(bounds.shape[0]), lens)
+    first = np.cumsum(lens) - lens
+    i_of = np.arange(w_of.size) - first[w_of]
+    pos = bounds[w_of, 0] + i_of
+    return w_of, i_of, pos
+
+
+def scatter_window_span_stats(index, not_best, feas,
                               span_not_best, span_cands) -> None:
     """Per-span confidence reductions over a packed window batch, written
     into the caller's ``[n_in]`` arrays in place: a span is "not best"
     when any endpoint's OT choice overrode the row argmax, and its
     candidate count is the product of per-endpoint feasible counts.
 
-    Vectorized over the packed window index (one fancy-gather per batch
-    instead of a Python loop per span) — decode sits on the dispatch
-    pipeline's critical path, so per-span Python work here would gate the
-    whole fleet solve.
+    Vectorized over the batch's :func:`window_index` (one fancy-gather
+    per batch instead of a Python loop per span) — decode sits on the
+    dispatch pipeline's critical path, so per-span Python work here
+    would gate the whole fleet solve.
     """
-    if not windows:
-        return
-    w_of = np.concatenate(
-        [np.full(hi - lo, b) for b, (lo, hi) in enumerate(windows)])
-    i_of = np.concatenate([np.arange(hi - lo) for lo, hi in windows])
-    pos = np.concatenate([np.arange(lo, hi) for lo, hi in windows])
+    w_of, i_of, pos = index
     span_not_best[pos] = not_best[w_of, :, i_of].any(axis=1)
     # int64 accumulator matches np.prod's platform-int promotion in the
     # scalar form this replaces
@@ -1054,8 +1070,9 @@ class PackedProblem:
 
     ``out_ids`` holds, per endpoint, either the object path's flat
     ``[B * M]`` id list or the columnar path's :class:`EndpointIds`
-    (id-table + ranges, gathered at decode time);
-    :meth:`out_id_array` is the single accessor decode reads through.
+    (id-table + ranges, gathered at decode time): decode reads either
+    through :func:`_candidate_keys`, and :meth:`out_id_array` gives
+    both in the object path's layout.
 
     ``devcols`` (``TW_DEVCOLS`` fleet path only) replaces the six big
     window tensors with ring-slot INDEX arrays plus the owning
@@ -1101,6 +1118,80 @@ class PackedProblem:
             else col[:n_rows * M]
             for col in self.out_ids
         ]
+
+
+#: decode's integer codes for the two sentinels; a real candidate's code
+#: is its id-table key (>= 0)
+NA_KEY = -1
+SKIP_KEY = -2
+
+
+def _candidate_keys(col, M: int, w: np.ndarray, cols: np.ndarray):
+    """Integer keys of candidate columns ``cols`` (0..M-1) of window rows
+    ``w`` for one endpoint, :data:`NA_KEY` where the slot holds no id,
+    and the id table the keys index.
+
+    On the columnar path (:class:`EndpointIds`) a key is the candidate's
+    row in the endpoint's id table, so equal keys are the same out-span
+    across every window of the batch. On the object path it is the
+    batch slot ``w * M + col``: unique within a window row only.
+    """
+    if isinstance(col, EndpointIds):
+        return (np.where(cols < col.count[w], col.r0[w] + cols, NA_KEY),
+                col.table)
+    ids = np.empty(len(col), dtype=object)
+    ids[:] = col
+    slot = w * M + cols
+    return np.where(ids[slot] != None, slot, NA_KEY), ids  # noqa: E711
+
+
+def _claims_in_order(assign_map, in_ids) -> Tuple[Dict, int]:
+    """Every claimed out-span with the positions (into ``in_ids``) of the
+    incoming spans that claimed it, in order of first claim, and the
+    number of SKIP answers."""
+    claims: Dict = {}
+    skips_used = 0
+    for p, in_id in enumerate(in_ids):
+        out_id = assign_map.get(in_id)
+        if out_id == SKIP:
+            skips_used += 1
+        elif out_id is not None and out_id != NA:
+            claims.setdefault(out_id, []).append(p)
+    return claims, skips_used
+
+
+def _reassign_losers(assign_map, topk_map, in_ids, claims, used: set,
+                     skips_used: int, budget: int) -> List:
+    """The serial rule of
+    :meth:`WeaverTPU._resolve_cross_window_duplicates` over ``claims``
+    (position lists, first claimant first, in order of first claim):
+    every later claimant takes its best-ranked top-K alternative that
+    ``used`` does not hold, SKIP only while ``budget`` has room, else NA.
+    Returns the positions of the claimants it reassigned."""
+    losers = []
+    for claimants in claims:
+        for p in claimants[1:]:  # earliest claimant keeps it
+            in_id = in_ids[p]
+            replacement = NA
+            for cand in topk_map.get(in_id, []):
+                if cand == SKIP:
+                    if skips_used < budget:
+                        replacement = SKIP
+                        skips_used += 1
+                        break
+                    continue
+                if cand != NA and cand not in used:
+                    replacement = cand
+                    break
+            assign_map[in_id] = replacement
+            if replacement not in (NA, SKIP):
+                used.add(replacement)
+            tk = topk_map.get(in_id)
+            if tk and replacement in tk:
+                tk.remove(replacement)
+                tk.insert(0, replacement)
+            losers.append(p)
+    return losers
 
 
 def _problem_tables(out_eps: List[str], E_pad: int,
@@ -1908,16 +1999,22 @@ class WeaverTPU:
 
     @staticmethod
     def _decode(packed: PackedProblem, assign: np.ndarray,
-                topk_cols: np.ndarray, all_assignments, all_topk):
+                topk_cols: np.ndarray, all_assignments, all_topk,
+                index) -> List[np.ndarray]:
         """Device indices -> wire-format assignment dicts (merged in place).
 
-        Vectorized: column indices for a whole packed batch are translated
-        to span ids by one object-array gather per endpoint (the id tables
-        are [B*M] object arrays), so per-span Python work is only the final
-        dict insertion — not index arithmetic (at exp5 scale the decode is
-        otherwise host-bound).
+        Whole-array passes per endpoint: each span's chosen column and
+        its top-K columns become integer keys (:func:`_candidate_keys`,
+        or :data:`NA_KEY` / :data:`SKIP_KEY`), the chosen key moves to
+        the front of its top-K row (its first match dropped), the ids are
+        gathered into one ``[n, K]`` object array, and one ``.tolist()``
+        gives every span its list. ``index`` is the batch's
+        :func:`window_index`.
+
+        Returns each endpoint's chosen keys ``[n]`` in index order, for
+        :meth:`_resolve_contested`.
         """
-        B, E, W = assign.shape
+        w_of, i_of, pos = index
         M = packed.M
         K = topk_cols.shape[3]
         # 0-d object holders let tuple sentinels assign under boolean masks
@@ -1926,51 +2023,44 @@ class WeaverTPU:
         na_v = np.empty((), dtype=object)
         na_v[()] = NA
 
-        w_of = np.concatenate(
-            [np.full(hi - lo, b) for b, (lo, hi) in enumerate(packed.windows)]
-        )
-        i_of = np.concatenate(
-            [np.arange(hi - lo) for lo, hi in packed.windows]
-        )
-        pos = np.concatenate([np.arange(lo, hi) for lo, hi in packed.windows])
         if isinstance(packed.in_ids, np.ndarray):
             # columnar: the id column gathers by position in one step
             span_ids = packed.in_ids[pos].tolist()
         else:
             span_ids = [packed.in_ids[p] for p in pos]
-
+        t = np.arange(K - 1)
+        chosen_keys = []
         for e, ep in enumerate(packed.out_eps):
-            # id maps resolve HERE (EndpointIds.gather on the columnar
-            # path): pack never materializes B*M Python id slots
-            ids = packed.out_id_array(e)
-
-            cols = assign[w_of, e, i_of]                       # [n]
-            chosen = ids[w_of * M + np.clip(cols, 0, M - 1)]
-            chosen[chosen == None] = na_v  # noqa: E711 — elementwise None test
-            chosen[cols < 0] = na_v
-            chosen[cols == M] = skip_v
-
-            tk = topk_cols[w_of, e, i_of, :]                   # [n, K]
-            tk_ids = ids[w_of[:, None] * M + np.clip(tk, 0, M - 1)]
-            tk_ids[tk_ids == None] = na_v  # noqa: E711
-            tk_ids[(tk < 0) | (tk > M)] = na_v
-            tk_ids[tk == M] = skip_v
-
-            amap = all_assignments[ep]
-            tmap = all_topk[ep]
-            chosen_l = chosen.tolist()
-            tk_l = tk_ids.tolist()
-            for j, in_id in enumerate(span_ids):
-                out_id = chosen_l[j]
-                tks = tk_l[j]
-                if out_id in tks:
-                    tks.remove(out_id)
-                amap[in_id] = out_id
-                tmap[in_id] = [out_id] + tks[: K - 1]
+            # column 0 the chosen column, then the top-K: a chosen column
+            # past M clips to the last slot, a top-K one is NA
+            raw = np.concatenate([assign[w_of, e, i_of][:, None],
+                                  topk_cols[w_of, e, i_of, :]], axis=1)
+            key, table = _candidate_keys(packed.out_ids[e], M, w_of[:, None],
+                                         np.clip(raw, 0, M - 1))
+            key[raw < 0] = NA_KEY
+            key[:, 1:][raw[:, 1:] > M] = NA_KEY
+            key[raw == M] = SKIP_KEY
+            chosen, tk = key[:, 0], key[:, 1:]
+            # [chosen] + top-K without its first match of chosen, cut to K
+            match = tk == chosen[:, None]
+            cut = np.where(match.any(axis=1), match.argmax(axis=1), K - 1)
+            row = np.concatenate(
+                [chosen[:, None],
+                 np.take_along_axis(tk, t + (t >= cut[:, None]), axis=1)],
+                axis=1)
+            ids = np.empty(row.shape, dtype=object)
+            real = row >= 0
+            ids[real] = table[row[real]]
+            ids[row == NA_KEY] = na_v
+            ids[row == SKIP_KEY] = skip_v
+            all_assignments[ep].update(zip(span_ids, ids[:, 0].tolist()))
+            all_topk[ep].update(zip(span_ids, ids.tolist()))
+            chosen_keys.append(chosen)
+        return chosen_keys
 
     @staticmethod
     def _resolve_cross_window_duplicates(all_assignments, all_topk, in_ids,
-                                         skip_budget):
+                                         skip_budget) -> int:
         """Restore global one-to-one-ness across capped sub-windows.
 
         Perfect-cut segments are solved whole, so duplicates can only arise
@@ -1982,37 +2072,98 @@ class WeaverTPU:
         top-K alternative that no row (winner or not) holds, taking SKIP
         only while the endpoint's global ``|in| - |out|`` budget
         (traceweaver_v3.py:972) has room, else NA.
+
+        Works on any dicts, however many batches filled them; returns the
+        number of contested out-spans. :meth:`_resolve_contested` gives
+        the same answers for one columnar batch from its integer keys.
         """
+        n_contested = 0
         for ep, assign_map in all_assignments.items():
-            claims: Dict = {}
-            skips_used = 0
-            for in_id in in_ids:
-                out_id = assign_map.get(in_id)
-                if out_id == SKIP:
-                    skips_used += 1
-                elif out_id is not None and out_id != NA:
-                    claims.setdefault(out_id, []).append(in_id)
-            used = set(claims)
-            for out_id, claimants in claims.items():
-                for in_id in claimants[1:]:  # earliest claimant keeps it
-                    replacement = NA
-                    for cand in all_topk.get(ep, {}).get(in_id, []):
-                        if cand == SKIP:
-                            if skips_used < skip_budget.get(ep, 0):
-                                replacement = SKIP
-                                skips_used += 1
-                                break
-                            continue
-                        if cand != NA and cand not in used:
-                            replacement = cand
-                            break
-                    assign_map[in_id] = replacement
-                    if replacement not in (NA, SKIP):
-                        used.add(replacement)
-                    tk = all_topk.get(ep, {}).get(in_id)
-                    if tk and replacement in tk:
-                        tk.remove(replacement)
-                        tk.insert(0, replacement)
+            claims, skips_used = _claims_in_order(assign_map, in_ids)
+            contested = [c for c in claims.values() if len(c) > 1]
+            n_contested += len(contested)
+            _reassign_losers(assign_map, all_topk.get(ep, {}), in_ids,
+                             contested, set(claims), skips_used,
+                             skip_budget.get(ep, 0))
+        return n_contested
+
+    @staticmethod
+    def _resolve_contested(packed: PackedProblem, chosen_keys, index,
+                           in_ids, all_assignments, all_topk,
+                           skip_budget) -> Tuple[np.ndarray, int]:
+        """:meth:`_resolve_cross_window_duplicates` for the dicts one
+        batch filled (:meth:`_decode` and its ``chosen_keys``): the rule
+        runs only on the out-spans claimed more than once, which it
+        alone changes.
+
+        On the columnar path equal keys are the same out-span, so one
+        ``bincount`` finds the contested ones and the SKIP count is one
+        vector count; the object path's keys are batch slots, so it
+        takes the dict loop. Returns each span's "some endpoint is NA"
+        flag after the rule, in index order, and the number of contested
+        out-spans.
+        """
+        pos = index[2]
+        row_of = np.empty(len(in_ids), dtype=np.int64)
+        row_of[pos] = np.arange(pos.size)
+        unassigned = np.zeros(pos.size, dtype=bool)
+        n_contested = 0
+        for e, ep in enumerate(packed.out_eps):
+            chosen = chosen_keys[e]
+            na = chosen == NA_KEY
+            assign_map, topk_map = all_assignments[ep], all_topk[ep]
+            col = packed.out_ids[e]
+            if isinstance(col, EndpointIds):
+                real = np.flatnonzero(chosen >= 0)
+                counts = np.bincount(chosen[real])
+                hot = real[counts[chosen[real]] > 1]
+                hot = hot[np.argsort(pos[hot], kind="stable")]
+                claims: Dict = {}
+                for p, k in zip(pos[hot].tolist(), chosen[hot].tolist()):
+                    claims.setdefault(k, []).append(p)
+                used = set(col.table[np.flatnonzero(counts)].tolist()) \
+                    if claims else set()
+                skips_used = int(np.count_nonzero(chosen == SKIP_KEY))
+                contested = list(claims.values())
+            else:
+                claims, skips_used = _claims_in_order(assign_map, in_ids)
+                contested = [c for c in claims.values() if len(c) > 1]
+                used = set(claims)
+            n_contested += len(contested)
+            for p in _reassign_losers(assign_map, topk_map, in_ids,
+                                      contested, used, skips_used,
+                                      skip_budget.get(ep, 0)):
+                na[row_of[p]] = assign_map[in_ids[p]] == NA
+            unassigned |= na
+        return unassigned, n_contested
+
+    @staticmethod
+    def _decode_batches(batches, out_eps, in_ids, skip_budget,
+                        conf_arrs=None):
+        """The per-service decode: every batch of one problem into one
+        set of dicts, then the duplicate rule over all of them. Batches
+        share no integer key space, so the rule takes the dict loop.
+        Returns the dicts, each span's not-best flag and candidate count,
+        and the number of contested out-spans; the quality reductions go
+        to ``conf_arrs`` when given."""
+        all_assignments = {ep: {} for ep in out_eps}
+        all_topk = {ep: {} for ep in out_eps}
+        # confidence: a span is "not best" if OT overrode the row argmax
+        span_not_best = np.zeros(len(in_ids), dtype=bool)
+        span_cands = np.ones(len(in_ids), dtype=np.int64)
+        for packed, (assign, topk_cols, not_best, feas) in batches:
+            index = window_index(packed.windows)
+            WeaverTPU._decode(packed, assign, topk_cols,
+                              all_assignments, all_topk, index)
+            scatter_window_span_stats(index, not_best, feas,
+                                      span_not_best, span_cands)
+            if conf_arrs is not None:
+                _quality.scatter_confidence(index, not_best, feas,
+                                            topk_cols, conf_arrs)
+        n_contested = WeaverTPU._resolve_cross_window_duplicates(
+            all_assignments, all_topk, in_ids, skip_budget)
+        return all_assignments, all_topk, span_not_best, span_cands, \
+            n_contested
 
     # -- plugin entry point ------------------------------------------------
     def FindAssignments(self, method, process, in_span_partitions,
@@ -2058,30 +2209,17 @@ class WeaverTPU:
                 # the single fused dispatch already ran refit + pass 2
                 iterations = 1
             t0 = _time.perf_counter()
-            all_assignments = {ep: {} for ep in out_eps}
-            all_topk = {ep: {} for ep in out_eps}
-            # confidence: a span is "not best" if OT overrode the row argmax
-            span_not_best = np.zeros(n_in, dtype=bool)
-            span_cands = np.ones(n_in, dtype=np.int64)
             conf_on = _quality.conf_enabled()
             conf_arrs = _quality.new_span_arrays(n_in) if conf_on else None
-            for packed, (assign, topk_cols, not_best, feas) in batches:
-                self._decode(packed, assign, topk_cols,
-                             all_assignments, all_topk)
-                scatter_window_span_stats(packed.windows, not_best, feas,
-                                          span_not_best, span_cands)
-                if conf_on:
-                    _quality.scatter_confidence(packed.windows, not_best,
-                                                feas, topk_cols, conf_arrs)
+            (all_assignments, all_topk, span_not_best, span_cands,
+             n_contested) = self._decode_batches(
+                 batches, out_eps, in_ids, skip_budget, conf_arrs)
             not_best_count = int(span_not_best.sum())
-            per_span_candidates = {
-                in_ids[i]: int(span_cands[i]) for i in range(n_in)
-            }
+            per_span_candidates = dict(zip(in_ids, span_cands.tolist()))
             self.per_span_confidence = (_quality.confidence_records(
                 in_ids, _quality.finish_confidence(conf_arrs))
                 if conf_on else {})
-            self._resolve_cross_window_duplicates(
-                all_assignments, all_topk, in_ids, skip_budget)
+            _stat_add(self.stats, "decode_contested", n_contested)
             _stat_add(self.stats, "decode_s", _time.perf_counter() - t0)
             if it + 1 < iterations:
                 t0 = _time.perf_counter()

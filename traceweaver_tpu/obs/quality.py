@@ -102,14 +102,6 @@ def low_threshold() -> float:
 # per-span reductions over a packed window batch (host side, vectorized)
 # ---------------------------------------------------------------------------
 
-def _window_maps(windows: Sequence[Tuple[int, int]]):
-    w_of = np.concatenate(
-        [np.full(hi - lo, b) for b, (lo, hi) in enumerate(windows)])
-    i_of = np.concatenate([np.arange(hi - lo) for lo, hi in windows])
-    pos = np.concatenate([np.arange(lo, hi) for lo, hi in windows])
-    return w_of, i_of, pos
-
-
 def new_span_arrays(n_in: int, device: bool = False) -> Dict[str, np.ndarray]:
     """Preallocated per-span quality arrays a caller scatters batches
     into (:func:`scatter_confidence`) before :func:`finish_confidence`."""
@@ -124,7 +116,7 @@ def new_span_arrays(n_in: int, device: bool = False) -> Dict[str, np.ndarray]:
     return out
 
 
-def scatter_confidence(windows: Sequence[Tuple[int, int]],
+def scatter_confidence(index: Tuple[np.ndarray, np.ndarray, np.ndarray],
                        not_best: np.ndarray, feas: np.ndarray,
                        topk_cols: np.ndarray,
                        arrs: Dict[str, np.ndarray],
@@ -132,17 +124,17 @@ def scatter_confidence(windows: Sequence[Tuple[int, int]],
                        entropy_q: Optional[np.ndarray] = None) -> None:
     """Scatter one packed batch's per-span quality reductions into
     ``arrs`` (in place, at the windows' span positions). Vectorized over
-    the packed index — decode sits on the dispatch pipeline's critical
-    path, so per-span Python here would gate the solve exactly like the
-    pack loops the columnar path killed.
+    the batch's ``(w_of, i_of, pos)`` index
+    (:func:`traceweaver_tpu.algorithms.weaver_tpu.window_index`) —
+    decode sits on the dispatch pipeline's critical path, so per-span
+    Python here would gate the solve exactly like the pack loops the
+    columnar path killed.
 
     Endpoint reductions are weakest-link by construction — a span is
     exactly right only if EVERY endpoint is: override = any, candidate
     count = product, support = max, margin = min, entropy = max.
     """
-    if not windows:
-        return
-    w_of, i_of, pos = _window_maps(windows)
+    w_of, i_of, pos = index
     arrs["not_best"][pos] = not_best[w_of, :, i_of].any(axis=1)
     arrs["cands"][pos] = np.maximum(
         feas[w_of, :, i_of], 1).astype(np.int64).prod(axis=1)
@@ -162,20 +154,20 @@ def finish_confidence(arrs: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
     return arrs
 
 
-def span_confidence_arrays(windows: Sequence[Tuple[int, int]],
+def span_confidence_arrays(index: Tuple[np.ndarray, np.ndarray, np.ndarray],
                            block: np.ndarray, n_in: int,
                            device: bool = False) -> Dict[str, np.ndarray]:
     """Per-span quality arrays reduced from one packed window batch.
 
     ``block`` is the ``[B, E, W, C]`` packed solver output
-    (:mod:`traceweaver_tpu.algorithms.packed_layout`); ``windows`` are the
-    batch's [lo, hi) index pairs into the item's sorted incoming spans
-    (they tile [0, n_in)). Returns ``{"not_best", "cands", "support",
+    (:mod:`traceweaver_tpu.algorithms.packed_layout`); ``index`` is the
+    batch's ``(w_of, i_of, pos)`` window index, whose windows tile
+    [0, n_in). Returns ``{"not_best", "cands", "support",
     "conf"[, "margin", "entropy"]}`` arrays of length ``n_in``.
     """
     ch = _layout.split_packed(block, confidence=device)
     arrs = new_span_arrays(n_in, device=device)
-    scatter_confidence(windows, ch["not_best"], ch["feas"],
+    scatter_confidence(index, ch["not_best"], ch["feas"],
                        ch["topk_cols"], arrs,
                        margin_q=ch.get("margin_q"),
                        entropy_q=ch.get("entropy_q"))
